@@ -2,6 +2,7 @@
 
 single pod : (16, 16)    axes ("data", "model")      = 256 chips
 multi pod  : (2, 16, 16) axes ("pod", "data", "model") = 512 chips
+one host   : (1, n)      axes ("data", "model")      = n local chips
 
 Defined as a function so importing this module never touches jax device
 state.  The dry-run launcher forces 512 host devices via XLA_FLAGS before
@@ -27,6 +28,19 @@ def make_production_mesh(*, multi_pod: bool = False):
         shape, axes,
         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
         devices=devices[:n])
+
+
+def make_chip_mesh(n_chips: int):
+    """(1, n_chips) ("data", "model") mesh over this host's first n_chips
+    devices: tensor parallelism across the chips of one host."""
+    devices = jax.devices()
+    if len(devices) < n_chips:
+        raise RuntimeError(
+            f"mesh (1, {n_chips}) needs {n_chips} devices, have {len(devices)}")
+    return jax.make_mesh(
+        (1, n_chips), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=devices[:n_chips])
 
 
 def make_host_mesh():

@@ -32,7 +32,9 @@ def cosine_schedule(base_lr, warmup, total):
 
 
 def adamw_init(params):
-    f32 = lambda t: jax.tree.map(lambda x: x.astype(jnp.float32), t)
+    # a copy even where params are already f32, so the train state never
+    # holds one buffer twice (a donated state must not)
+    f32 = lambda t: jax.tree.map(lambda x: jnp.array(x, jnp.float32), t)
     zeros = lambda t: jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), t)
     return {"master": f32(params), "mu": zeros(params), "nu": zeros(params)}
 

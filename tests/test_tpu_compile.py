@@ -1,0 +1,112 @@
+"""Compile the Pallas kernels at real widths for a described TPU v5e.
+
+Nothing runs: the TPU compiler is asked for each program against a
+``v5e:2x2`` topology description, which refuses what the chip would refuse
+(block shapes off the (8, 128) tiling, too much VMEM, a program that does not
+fit).  Interpret-mode tests cannot see those faults.
+
+The topology and everything built from it are made inside module-scoped
+fixtures, never at import: only one process at a time may load the TPU
+library, and every test worker imports every test file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rglru_scan import rglru_scan
+from repro.kernels.rwkv6_wkv import rwkv6_wkv
+from repro.sharding import use_mesh_rules
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile_for_chip(fn, *args):
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    return hlo
+
+
+# yi-9b attention widths: 32 query heads, 4 kv heads, head_dim 128
+def _yi_qkv(sharding, S):
+    q = jax.ShapeDtypeStruct((1, S, 32, 128), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((1, S, 4, 128), jnp.bfloat16, sharding=sharding)
+    return q, kv, kv
+
+
+def test_flash_attention_forward_compiles(one_chip, no_compile_cache):
+    _compile_for_chip(
+        lambda q, k, v: flash_attention(q, k, v, backend="pallas"),
+        *_yi_qkv(one_chip, 32768))
+
+
+def test_flash_attention_grad_compiles(one_chip, no_compile_cache):
+    # a loss whose gradient needs the forward output, so the kernel stays in
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, backend="pallas")
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    _compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                      *_yi_qkv(one_chip, 32768))
+
+
+def test_flash_attention_head_sharded_compiles(topo, no_compile_cache):
+    """Four chips, heads over "model": the kernel runs per head shard."""
+    mesh = jax.sharding.Mesh(
+        [[d for d in topo.devices]], ("data", "model"))
+    rules = {"batch": "data", "heads": "model"}
+    spec = NamedSharding(mesh, P("data", None, "model", None))
+
+    def fwd(q, k, v):
+        with use_mesh_rules(mesh, rules):
+            return flash_attention(q, k, v, backend="pallas")
+
+    hlo = _compile_for_chip(fwd, *_yi_qkv(spec, 8192))
+    assert "all-gather" not in hlo
+
+
+def test_rwkv6_wkv_compiles(one_chip, no_compile_cache):
+    # rwkv6-7b: 64 heads of 64
+    x = jax.ShapeDtypeStruct((1, 4096, 64, 64), jnp.bfloat16, sharding=one_chip)
+    u = jax.ShapeDtypeStruct((64, 64), jnp.float32, sharding=one_chip)
+    s0 = jax.ShapeDtypeStruct((1, 64, 64, 64), jnp.float32, sharding=one_chip)
+    _compile_for_chip(
+        lambda r, k, v, w, u, s0: rwkv6_wkv(r, k, v, w, u, s0,
+                                            backend="pallas"),
+        x, x, x, x, u, s0)
+
+
+def test_rglru_scan_compiles(one_chip, no_compile_cache):
+    # recurrentgemma-2b: lru width 2560, batch 2
+    a = jax.ShapeDtypeStruct((2, 4096, 2560), jnp.bfloat16, sharding=one_chip)
+    h0 = jax.ShapeDtypeStruct((2, 2560), jnp.float32, sharding=one_chip)
+    _compile_for_chip(
+        lambda a, b, h0: rglru_scan(a, b, h0, backend="pallas"), a, a, h0)
