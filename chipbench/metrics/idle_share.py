@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of the device's operation intervals) / window, averaged over the
+chips used (``chipbench/trace_reduce.py``)."""
+
+
+def read(run):
+    t = run.get("trace")
+    if t is None or t.window_s <= 0 or t.devices == 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
