@@ -8,6 +8,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.flash_attention import decode_attention, flash_attention
 from repro.sharding import constrain
 
@@ -34,54 +35,63 @@ def gqa_block(p, x, *, cfg, positions, mode, cache, pos=None, window=None):
     absolute position of the current token (decode only).
     Returns (residual_out, new_cache).
     """
-    y = rms_norm(x, p["ln1"])
-    q = jnp.einsum("bsd,dhk->bshk", y, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", y, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", y, p["wv"])
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    q = constrain(q, "batch", "seq", "heads", "head_dim")
-    k = constrain(k, "batch", "seq", "kv_heads", "head_dim")
+    with obs.scope(obs.ATTN_QKV):
+        y = rms_norm(x, p["ln1"])
+        q = jnp.einsum("bsd,dhk->bshk", y, p["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", y, p["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", y, p["wv"])
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"])
+            k = rms_norm(k, p["k_norm"])
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        q = constrain(q, "batch", "seq", "heads", "head_dim")
+        k = constrain(k, "batch", "seq", "kv_heads", "head_dim")
 
     new_cache = None
     if mode == "decode":
-        kc = _write_cache(cache["k"], k, pos, window)
-        vc = _write_cache(cache["v"], v, pos, window)
-        kc = constrain(kc, "batch", "kv_seq", "kv_heads", "head_dim")
-        vc = constrain(vc, "batch", "kv_seq", "kv_heads", "head_dim")
-        length = jnp.minimum(pos + 1, window) if window else pos + 1
-        out = decode_attention(
-            q, kc, vc, length,
-            logits_constraint=lambda s: constrain(
-                s, "batch", None, "kv_heads", None, "kv_seq"))
+        with obs.scope(obs.ATTN_CACHE_WRITE):
+            kc = _write_cache(cache["k"], k, pos, window)
+            vc = _write_cache(cache["v"], v, pos, window)
+            kc = constrain(kc, "batch", "kv_seq", "kv_heads", "head_dim")
+            vc = constrain(vc, "batch", "kv_seq", "kv_heads", "head_dim")
+        with obs.scope(obs.ATTN_CORE):
+            length = jnp.minimum(pos + 1, window) if window else pos + 1
+            out = decode_attention(
+                q, kc, vc, length,
+                logits_constraint=lambda s: constrain(
+                    s, "batch", None, "kv_heads", None, "kv_seq"))
         new_cache = {"k": kc, "v": vc}
     else:
-        out = flash_attention(q, k, v, causal=cfg.causal, window=window)
+        with obs.scope(obs.ATTN_CORE):
+            out = flash_attention(q, k, v, causal=cfg.causal, window=window)
         if mode == "prefill":
-            S = x.shape[1]
-            if window is not None and window < S:
-                # keep the trailing window in ring order: slot = pos % window
-                tail = jax.lax.dynamic_slice_in_dim(k, S - window, window, 1)
-                tailv = jax.lax.dynamic_slice_in_dim(v, S - window, window, 1)
-                shift = S % window
-                kc = jnp.roll(tail, shift, axis=1)
-                vc = jnp.roll(tailv, shift, axis=1)
-            else:
-                pad = (cache["k"].shape[1] - S) if cache else 0
-                kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            new_cache = {"k": kc.astype(cache["k"].dtype),
-                         "v": vc.astype(cache["v"].dtype)}
-    out = constrain(out, "batch", "seq", "heads", "head_dim")
-    if cfg.padded_heads != cfg.n_heads:
-        # zero the padded heads so the padded model == the assigned model
-        hmask = (jnp.arange(cfg.padded_heads) < cfg.n_heads).astype(out.dtype)
-        out = out * hmask[None, None, :, None]
-    o = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return x + o, new_cache
+            with obs.scope(obs.ATTN_CACHE_WRITE):
+                new_cache = _prefill_cache(k, v, cache, x.shape[1], window)
+    with obs.scope(obs.ATTN_OUT):
+        out = constrain(out, "batch", "seq", "heads", "head_dim")
+        if cfg.padded_heads != cfg.n_heads:
+            # zero the padded heads so the padded model == the assigned model
+            hmask = (jnp.arange(cfg.padded_heads) < cfg.n_heads).astype(out.dtype)
+            out = out * hmask[None, None, :, None]
+        o = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+        return x + o, new_cache
+
+
+def _prefill_cache(k, v, cache, S, window):
+    """The cache a prefill of S tokens leaves: k/v padded to the cache's
+    length, or their trailing window in ring order (slot = pos % window)."""
+    if window is not None and window < S:
+        tail = jax.lax.dynamic_slice_in_dim(k, S - window, window, 1)
+        tailv = jax.lax.dynamic_slice_in_dim(v, S - window, window, 1)
+        shift = S % window
+        kc = jnp.roll(tail, shift, axis=1)
+        vc = jnp.roll(tailv, shift, axis=1)
+    else:
+        pad = (cache["k"].shape[1] - S) if cache else 0
+        kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    return {"k": kc.astype(cache["k"].dtype), "v": vc.astype(cache["v"].dtype)}
 
 
 def _mla_two_pass(q_abs, q_rope, ckv, krope, length, scale, constraint=None):
@@ -110,56 +120,63 @@ def _mla_two_pass(q_abs, q_rope, ckv, krope, length, scale, constraint=None):
 def mla_block(p, x, *, cfg, positions, mode, cache, pos=None, window=None):
     """Multi-head Latent Attention (DeepSeek-V2/MiniCPM3) residual branch."""
     m = cfg.mla
-    y = rms_norm(x, p["ln1"])
-    cq = rms_norm(jnp.einsum("bsd,dr->bsr", y, p["wq_a"]), p["q_a_norm"])
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"])  # (B,S,H,nope+rope)
-    q_nope, q_rope = jnp.split(q, [m.qk_nope_dim], axis=-1)
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    with obs.scope(obs.ATTN_QKV):
+        y = rms_norm(x, p["ln1"])
+        cq = rms_norm(jnp.einsum("bsd,dr->bsr", y, p["wq_a"]), p["q_a_norm"])
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"])  # (B,S,H,nope+rope)
+        q_nope, q_rope = jnp.split(q, [m.qk_nope_dim], axis=-1)
+        q_rope = rope(q_rope, positions, cfg.rope_theta)
 
-    ckv_full = jnp.einsum("bsd,dr->bsr", y, p["wkv_a"])
-    ckv, krope = jnp.split(ckv_full, [m.kv_lora_rank], axis=-1)
-    ckv = rms_norm(ckv, p["kv_a_norm"])
-    krope = rope(krope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+        ckv_full = jnp.einsum("bsd,dr->bsr", y, p["wkv_a"])
+        ckv, krope = jnp.split(ckv_full, [m.kv_lora_rank], axis=-1)
+        ckv = rms_norm(ckv, p["kv_a_norm"])
+        krope = rope(krope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
 
-    wkv_b_k = p["wkv_b"][:, :, : m.qk_nope_dim]      # (R, H, nope)
-    wkv_b_v = p["wkv_b"][:, :, m.qk_nope_dim:]       # (R, H, v)
+        wkv_b_k = p["wkv_b"][:, :, : m.qk_nope_dim]      # (R, H, nope)
+        wkv_b_v = p["wkv_b"][:, :, m.qk_nope_dim:]       # (R, H, v)
     scale = 1.0 / ((m.qk_nope_dim + m.qk_rope_dim) ** 0.5)
 
     new_cache = None
     if mode == "decode":
-        ckv_c = _write_cache(cache["ckv"][..., None], ckv[..., None], pos,
-                             None)[..., 0]
-        kr_c = _write_cache(cache["krope"][..., None], krope[..., None], pos,
-                            None)[..., 0]
-        ckv_c = constrain(ckv_c, "batch", "kv_seq", "lora")
-        kr_c = constrain(kr_c, "batch", "kv_seq", "qk_dim")
-        q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, wkv_b_k)
-        ctx = _mla_two_pass(
-            q_abs, q_rope, ckv_c, kr_c, pos + 1, scale,
-            constraint=lambda s: constrain(s, "batch", None, "heads", "kv_seq"))
-        out = jnp.einsum("bshr,rhv->bshv", ctx.astype(x.dtype), wkv_b_v)
+        with obs.scope(obs.ATTN_CACHE_WRITE):
+            ckv_c = _write_cache(cache["ckv"][..., None], ckv[..., None], pos,
+                                 None)[..., 0]
+            kr_c = _write_cache(cache["krope"][..., None], krope[..., None], pos,
+                                None)[..., 0]
+            ckv_c = constrain(ckv_c, "batch", "kv_seq", "lora")
+            kr_c = constrain(kr_c, "batch", "kv_seq", "qk_dim")
+        with obs.scope(obs.ATTN_CORE):
+            q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, wkv_b_k)
+            ctx = _mla_two_pass(
+                q_abs, q_rope, ckv_c, kr_c, pos + 1, scale,
+                constraint=lambda s: constrain(s, "batch", None, "heads", "kv_seq"))
+            out = jnp.einsum("bshr,rhv->bshv", ctx.astype(x.dtype), wkv_b_v)
         new_cache = {"ckv": ckv_c, "krope": kr_c}
     else:
-        k_nope = jnp.einsum("bsr,rhn->bshn", ckv, wkv_b_k)
-        v = jnp.einsum("bsr,rhv->bshv", ckv, wkv_b_v)
-        H = k_nope.shape[2]  # padded head count
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(krope[:, :, None, :],
-                                      k_nope.shape[:2] + (H, m.qk_rope_dim))],
-            axis=-1)
-        qq = jnp.concatenate([q_nope, q_rope], axis=-1)
-        out = flash_attention(qq, k, v, causal=cfg.causal, window=window)
+        with obs.scope(obs.ATTN_QKV):
+            k_nope = jnp.einsum("bsr,rhn->bshn", ckv, wkv_b_k)
+            v = jnp.einsum("bsr,rhv->bshv", ckv, wkv_b_v)
+            H = k_nope.shape[2]  # padded head count
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(krope[:, :, None, :],
+                                          k_nope.shape[:2] + (H, m.qk_rope_dim))],
+                axis=-1)
+            qq = jnp.concatenate([q_nope, q_rope], axis=-1)
+        with obs.scope(obs.ATTN_CORE):
+            out = flash_attention(qq, k, v, causal=cfg.causal, window=window)
         if mode == "prefill":
-            Smax = cache["ckv"].shape[1]
-            pad = Smax - ckv.shape[1]
-            new_cache = {
-                "ckv": jnp.pad(ckv, ((0, 0), (0, pad), (0, 0))).astype(
-                    cache["ckv"].dtype),
-                "krope": jnp.pad(krope, ((0, 0), (0, pad), (0, 0))).astype(
-                    cache["krope"].dtype),
-            }
-    if cfg.padded_heads != cfg.n_heads:
-        hmask = (jnp.arange(cfg.padded_heads) < cfg.n_heads).astype(out.dtype)
-        out = out * hmask[None, None, :, None]
-    o = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
-    return x + o, new_cache
+            with obs.scope(obs.ATTN_CACHE_WRITE):
+                Smax = cache["ckv"].shape[1]
+                pad = Smax - ckv.shape[1]
+                new_cache = {
+                    "ckv": jnp.pad(ckv, ((0, 0), (0, pad), (0, 0))).astype(
+                        cache["ckv"].dtype),
+                    "krope": jnp.pad(krope, ((0, 0), (0, pad), (0, 0))).astype(
+                        cache["krope"].dtype),
+                }
+    with obs.scope(obs.ATTN_OUT):
+        if cfg.padded_heads != cfg.n_heads:
+            hmask = (jnp.arange(cfg.padded_heads) < cfg.n_heads).astype(out.dtype)
+            out = out * hmask[None, None, :, None]
+        o = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
+        return x + o, new_cache

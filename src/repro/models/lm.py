@@ -13,6 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.sharding import constrain, spec_for
 from repro.types import ArchConfig
 
@@ -139,16 +140,24 @@ def _block_apply(kind, p, x, *, cfg, positions, mode, cache, pos):
     else:
         raise ValueError(kind)
     if cfg.moe is not None:
-        x = moe_block(p, x, cfg=cfg)
+        with obs.scope(obs.MOE):
+            x = moe_block(p, x, cfg=cfg)
     else:
-        mlp_p = {k[4:]: p[k] for k in ("mlp_wg", "mlp_wu", "mlp_wo") if k in p}
-        x = x + mlp_apply(mlp_p, rms_norm(x, p["ln2"]), cfg.mlp_kind)
+        with obs.scope(obs.MLP):
+            mlp_p = {k[4:]: p[k] for k in ("mlp_wg", "mlp_wu", "mlp_wo") if k in p}
+            x = x + mlp_apply(mlp_p, rms_norm(x, p["ln2"]), cfg.mlp_kind)
     x = constrain(x, "batch", "seq", "embed")
     return x, new_cache
 
 
 def _run_stack(params, cfg, x, positions, mode, cache, remat="full",
                remat_group=8):
+    with obs.scope(obs.LAYERS):
+        return _run_layers(params, cfg, x, positions, mode, cache, remat,
+                           remat_group)
+
+
+def _run_layers(params, cfg, x, positions, mode, cache, remat, remat_group):
     kinds = cfg.layer_kinds()
     pos = None if cache is None else cache["pos"]
     layer_caches = None if cache is None else cache["layers"]
@@ -210,8 +219,8 @@ def _run_stack(params, cfg, x, positions, mode, cache, remat="full",
 # ---------------------------------------------------------------------------
 
 def _embed_tokens(params, cfg, tokens):
-    x = jnp.take(params["embed"], tokens, axis=0)
-    return x
+    with obs.scope(obs.EMBED):
+        return jnp.take(params["embed"], tokens, axis=0)
 
 
 def _head_weight(params, cfg):
@@ -237,7 +246,8 @@ def forward(params, cfg: ArchConfig, *, tokens=None, embeds=None,
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     x, new_layer_caches = _run_stack(params, cfg, x, positions, mode, cache,
                                      remat=remat, remat_group=remat_group)
-    x = rms_norm(x, params["final_norm"])
+    with obs.scope(obs.HEAD):
+        x = rms_norm(x, params["final_norm"])
     new_cache = None
     if mode in ("prefill", "decode"):
         base = S if mode == "prefill" else 1
@@ -252,8 +262,9 @@ def loss_fn(params, cfg: ArchConfig, batch, *, remat="full", ce_chunk=512,
     x, _ = forward(params, cfg, tokens=batch.get("tokens"),
                    embeds=batch.get("embeds"), mode="train", remat=remat,
                    remat_group=remat_group)
-    head_w = _head_weight(params, cfg)
-    loss, count = chunked_ce_loss(x, head_w, batch["labels"], chunk=ce_chunk)
+    with obs.scope(obs.LOSS):
+        head_w = _head_weight(params, cfg)
+        loss, count = chunked_ce_loss(x, head_w, batch["labels"], chunk=ce_chunk)
     return loss, {"tokens": count}
 
 
@@ -268,18 +279,20 @@ def prefill(params, cfg: ArchConfig, cache, *, tokens=None, embeds=None):
         return logits, None
     x, new_cache = forward(params, cfg, tokens=tokens, embeds=embeds,
                            mode="prefill", cache=cache)
-    head_w = _head_weight(params, cfg)
-    last = x[:, -1:, :]
-    logits = jnp.einsum("bsd,dv->bsv", last, head_w,
-                        preferred_element_type=jnp.float32)
-    return logits[:, 0], new_cache
+    with obs.scope(obs.HEAD):
+        head_w = _head_weight(params, cfg)
+        last = x[:, -1:, :]
+        logits = jnp.einsum("bsd,dv->bsv", last, head_w,
+                            preferred_element_type=jnp.float32)
+        return logits[:, 0], new_cache
 
 
 def decode_step(params, cfg: ArchConfig, cache, tokens):
     """One decode step.  tokens: (B, 1) int32.  Returns (logits, cache)."""
     x, new_cache = forward(params, cfg, tokens=tokens, mode="decode",
                            cache=cache)
-    head_w = _head_weight(params, cfg)
-    logits = jnp.einsum("bsd,dv->bsv", x, head_w,
-                        preferred_element_type=jnp.float32)
-    return logits[:, 0], new_cache
+    with obs.scope(obs.HEAD):
+        head_w = _head_weight(params, cfg)
+        logits = jnp.einsum("bsd,dv->bsv", x, head_w,
+                            preferred_element_type=jnp.float32)
+        return logits[:, 0], new_cache

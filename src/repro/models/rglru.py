@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.rglru_scan import rglru_scan
 from repro.models.schema import RGLRU_BLOCKS
 from repro.sharding import constrain
@@ -56,6 +57,11 @@ def _lru_coeffs(p, r, i, xb):
 
 def rglru_block(p, x, *, cfg, mode, cache):
     """Recurrent residual branch.  x: (B,S,D).  Returns (out, new_cache)."""
+    with obs.scope(obs.RGLRU):
+        return _rglru_branch(p, x, cfg=cfg, mode=mode, cache=cache)
+
+
+def _rglru_branch(p, x, *, cfg, mode, cache):
     B, S, D = x.shape
     W = cfg.lru_width or D
     y = rms_norm(x, p["ln1"])

@@ -8,6 +8,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.rwkv6_wkv import rwkv6_wkv
 from repro.sharding import constrain
 
@@ -69,30 +70,30 @@ def rwkv_block(p, x, *, cfg, mode, cache):
     B, S, D = x.shape
     new_cache = None
 
-    # --- time mix ---
-    y = rms_norm(x, p["ln1"])
-    if mode == "decode":
-        x_prev = cache["x_tm"][:, None, :].astype(y.dtype)
-        state = cache["s"]
-    else:
-        x_prev = _shift(y, None)
-        state = None
-    tm_out, s_last = _time_mix(p, y, x_prev, cfg=cfg, state=state)
-    x = x + tm_out
+    with obs.scope(obs.RWKV_TIME_MIX):
+        y = rms_norm(x, p["ln1"])
+        if mode == "decode":
+            x_prev = cache["x_tm"][:, None, :].astype(y.dtype)
+            state = cache["s"]
+        else:
+            x_prev = _shift(y, None)
+            state = None
+        tm_out, s_last = _time_mix(p, y, x_prev, cfg=cfg, state=state)
+        x = x + tm_out
 
-    # --- channel mix ---
-    y2 = rms_norm(x, p["ln2"])
-    if mode == "decode":
-        y2_prev = cache["x_cm"][:, None, :].astype(y2.dtype)
-    else:
-        y2_prev = _shift(y2, None)
-    dk = y2 + (y2_prev - y2) * p["cm_mu_k"].astype(y2.dtype)
-    dr = y2 + (y2_prev - y2) * p["cm_mu_r"].astype(y2.dtype)
-    kk = jax.nn.relu(jnp.einsum("bsd,df->bsf", dk, p["cm_k"]))
-    kk = constrain(kk * kk, "batch", "seq", "ffn")
-    cm = jnp.einsum("bsf,fd->bsd", kk, p["cm_v"])
-    rr = jax.nn.sigmoid(jnp.einsum("bsd,de->bse", dr, p["cm_r"]))
-    x = x + rr.astype(cm.dtype) * cm
+    with obs.scope(obs.RWKV_CHANNEL_MIX):
+        y2 = rms_norm(x, p["ln2"])
+        if mode == "decode":
+            y2_prev = cache["x_cm"][:, None, :].astype(y2.dtype)
+        else:
+            y2_prev = _shift(y2, None)
+        dk = y2 + (y2_prev - y2) * p["cm_mu_k"].astype(y2.dtype)
+        dr = y2 + (y2_prev - y2) * p["cm_mu_r"].astype(y2.dtype)
+        kk = jax.nn.relu(jnp.einsum("bsd,df->bsf", dk, p["cm_k"]))
+        kk = constrain(kk * kk, "batch", "seq", "ffn")
+        cm = jnp.einsum("bsf,fd->bsd", kk, p["cm_v"])
+        rr = jax.nn.sigmoid(jnp.einsum("bsd,de->bse", dr, p["cm_r"]))
+        x = x + rr.astype(cm.dtype) * cm
 
     if mode in ("prefill", "decode"):
         new_cache = {"s": s_last,

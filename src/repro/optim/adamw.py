@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
+
 
 def global_norm(tree):
     leaves = [jnp.sum(jnp.square(x.astype(jnp.float32)))
@@ -48,6 +50,12 @@ def init_train_state(params):
 
 def adamw_update(state, grads, *, lr, b1=0.9, b2=0.95, eps=1e-8,
                  weight_decay=0.1, clip=1.0):
+    with obs.scope(obs.OPTIMIZER):
+        return _adamw_update(state, grads, lr=lr, b1=b1, b2=b2, eps=eps,
+                             weight_decay=weight_decay, clip=clip)
+
+
+def _adamw_update(state, grads, *, lr, b1, b2, eps, weight_decay, clip):
     step = state["step"] + 1
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, clip / jnp.maximum(gnorm, 1e-12))

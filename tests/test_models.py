@@ -118,6 +118,28 @@ def test_local_attention_window_ring_buffer():
                                atol=3e-4, rtol=3e-4)
 
 
+@pytest.mark.parametrize("name,scopes", [
+    ("yi-9b", ("embed", "layers", "attn/qkv", "attn/core", "attn/out", "mlp",
+               "head")),
+    ("minicpm3-4b", ("layers", "attn/qkv", "attn/core", "attn/out")),
+    ("qwen3-moe-30b-a3b", ("layers", "attn/core", "moe")),
+    ("rwkv6-7b", ("layers", "rwkv/time_mix", "rwkv/channel_mix")),
+    ("recurrentgemma-2b", ("layers", "rglru", "attn/core", "mlp")),
+])
+def test_forward_names_its_layers(name, scopes):
+    """The layer scopes of ``repro/obs.py`` reach the compiled program as
+    ``op_name``s, so a device trace splits by layer."""
+    import re
+    cfg = ARCHS[name].reduced()
+    params = lm.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    tokens = jnp.zeros((1, 16), jnp.int32)
+    text = jax.jit(lambda p, t: lm.forward(p, cfg, tokens=t)[0]).lower(
+        params, tokens).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in names), scope
+
+
 @pytest.mark.parametrize("name", ARCH_NAMES)
 def test_param_count_matches_analytic(name):
     """Schema-materialized parameter count == logical params + the analytic
