@@ -126,18 +126,19 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
 def decode_attention(q, k_cache, v_cache, length, *, logits_constraint=None):
     """Single-step attention over a (possibly sequence-sharded) KV cache.
 
-    q: (B, 1, H, D); caches: (B, S, KH, D); ``length``: number of valid cache
-    entries (scalar int32).  Two-pass (global max, then weighted sum) so GSPMD
-    turns a sequence-sharded cache into two small all-reduces instead of an
-    all-gather of the cache.  ``logits_constraint``: optional fn applied to the
-    (B, 1, KH, G, S) logits to pin their sharding.
+    q: (B, 1, H, D); caches: (B, KH, S, D), heads-major; ``length``: number
+    of valid cache entries (scalar int32).  Two-pass (global max, then
+    weighted sum) so GSPMD turns a sequence-sharded cache into two small
+    all-reduces instead of an all-gather of the cache.
+    ``logits_constraint``: optional fn applied to the (B, 1, KH, G, S)
+    logits to pin their sharding.
     """
     B, _, H, D = q.shape
-    _, S, KH, _ = k_cache.shape
+    _, KH, S, _ = k_cache.shape
     group = H // KH
     scale = 1.0 / (D ** 0.5)
     qf = q.reshape(B, 1, KH, group, D)
-    s = jnp.einsum("bqhgd,bshd->bqhgs", qf, k_cache,
+    s = jnp.einsum("bqhgd,bhsd->bqhgs", qf, k_cache,
                    preferred_element_type=jnp.float32) * scale
     if logits_constraint is not None:
         s = logits_constraint(s)
@@ -145,7 +146,7 @@ def decode_attention(q, k_cache, v_cache, length, *, logits_constraint=None):
     s = jnp.where(mask, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)          # all-reduce(max) when sharded
     p = jnp.where(mask, jnp.exp(s - m), 0.0)
-    num = jnp.einsum("bqhgs,bshd->bqhgd", p, v_cache,
+    num = jnp.einsum("bqhgs,bhsd->bqhgd", p, v_cache,
                      preferred_element_type=jnp.float32)  # all-reduce(sum)
     den = jnp.sum(p, axis=-1, keepdims=False)
     out = num / jnp.maximum(den, 1e-30)[..., None]

@@ -17,22 +17,28 @@ from .layers import rms_norm, rope
 NEG_INF = -1e30
 
 
-def _write_cache(cache_kv, new, pos, ring: int | None):
-    """Insert new (B, S_new, KH, D) at position ``pos`` (ring-buffered if
-    ``ring``).  For S_new == 1 decode this is a dynamic_update_slice."""
-    if ring is None:
-        return jax.lax.dynamic_update_slice(
-            cache_kv, new.astype(cache_kv.dtype), (0, pos, 0, 0))
-    slot = pos % ring
-    return jax.lax.dynamic_update_slice(
-        cache_kv, new.astype(cache_kv.dtype), (0, slot, 0, 0))
+def _write_cache(cache, new, slot, seq_axis, layer=None):
+    """Write one step's entries ``new`` (size 1 on ``seq_axis``) at ``slot``
+    of a layer's cache, or, given ``layer``, of that layer of a stacked
+    cache: one dynamic_update_slice, in place."""
+    start = [0] * new.ndim
+    start[seq_axis] = slot
+    if layer is not None:
+        new, start = new[None], [layer] + start
+    return jax.lax.dynamic_update_slice(cache, new.astype(cache.dtype), start)
 
 
-def gqa_block(p, x, *, cfg, positions, mode, cache, pos=None, window=None):
+def _layer(cache, layer):
+    return cache if layer is None else cache[layer]
+
+
+def gqa_block(p, x, *, cfg, positions, mode, cache, pos=None, window=None,
+              layer=None):
     """Pre-norm GQA attention residual branch.
 
     x: (B, S, D); positions: (B, S) absolute positions; ``pos``: scalar
-    absolute position of the current token (decode only).
+    absolute position of the current token (decode only); ``layer``: this
+    layer's index where ``cache`` is the stacked cache of every layer.
     Returns (residual_out, new_cache).
     """
     with obs.scope(obs.ATTN_QKV):
@@ -51,14 +57,18 @@ def gqa_block(p, x, *, cfg, positions, mode, cache, pos=None, window=None):
     new_cache = None
     if mode == "decode":
         with obs.scope(obs.ATTN_CACHE_WRITE):
-            kc = _write_cache(cache["k"], k, pos, window)
-            vc = _write_cache(cache["v"], v, pos, window)
-            kc = constrain(kc, "batch", "kv_seq", "kv_heads", "head_dim")
-            vc = constrain(vc, "batch", "kv_seq", "kv_heads", "head_dim")
+            # the cache is heads-major: (B, KH, S, D)
+            slot = pos if window is None else pos % window
+            lead = () if layer is None else ("layers",)
+            axes = (*lead, "batch", "kv_heads", "kv_seq", "head_dim")
+            kc = constrain(_write_cache(cache["k"], k.transpose(0, 2, 1, 3),
+                                        slot, 2, layer), *axes)
+            vc = constrain(_write_cache(cache["v"], v.transpose(0, 2, 1, 3),
+                                        slot, 2, layer), *axes)
         with obs.scope(obs.ATTN_CORE):
             length = jnp.minimum(pos + 1, window) if window else pos + 1
             out = decode_attention(
-                q, kc, vc, length,
+                q, _layer(kc, layer), _layer(vc, layer), length,
                 logits_constraint=lambda s: constrain(
                     s, "batch", None, "kv_heads", None, "kv_seq"))
         new_cache = {"k": kc, "v": vc}
@@ -79,18 +89,17 @@ def gqa_block(p, x, *, cfg, positions, mode, cache, pos=None, window=None):
 
 
 def _prefill_cache(k, v, cache, S, window):
-    """The cache a prefill of S tokens leaves: k/v padded to the cache's
-    length, or their trailing window in ring order (slot = pos % window)."""
+    """The heads-major cache a prefill of S tokens leaves: k/v padded to the
+    cache's length, or their trailing window in ring order (slot = pos %
+    window)."""
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     if window is not None and window < S:
-        tail = jax.lax.dynamic_slice_in_dim(k, S - window, window, 1)
-        tailv = jax.lax.dynamic_slice_in_dim(v, S - window, window, 1)
         shift = S % window
-        kc = jnp.roll(tail, shift, axis=1)
-        vc = jnp.roll(tailv, shift, axis=1)
+        kc = jnp.roll(k[:, :, S - window:], shift, axis=2)
+        vc = jnp.roll(v[:, :, S - window:], shift, axis=2)
     else:
-        pad = (cache["k"].shape[1] - S) if cache else 0
-        kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        pad = ((0, 0), (0, 0), (0, cache["k"].shape[2] - S), (0, 0))
+        kc, vc = jnp.pad(k, pad), jnp.pad(v, pad)
     return {"k": kc.astype(cache["k"].dtype), "v": vc.astype(cache["v"].dtype)}
 
 
@@ -117,7 +126,8 @@ def _mla_two_pass(q_abs, q_rope, ckv, krope, length, scale, constraint=None):
     return num / jnp.maximum(den, 1e-30)
 
 
-def mla_block(p, x, *, cfg, positions, mode, cache, pos=None, window=None):
+def mla_block(p, x, *, cfg, positions, mode, cache, pos=None, window=None,
+              layer=None):
     """Multi-head Latent Attention (DeepSeek-V2/MiniCPM3) residual branch."""
     m = cfg.mla
     with obs.scope(obs.ATTN_QKV):
@@ -139,16 +149,16 @@ def mla_block(p, x, *, cfg, positions, mode, cache, pos=None, window=None):
     new_cache = None
     if mode == "decode":
         with obs.scope(obs.ATTN_CACHE_WRITE):
-            ckv_c = _write_cache(cache["ckv"][..., None], ckv[..., None], pos,
-                                 None)[..., 0]
-            kr_c = _write_cache(cache["krope"][..., None], krope[..., None], pos,
-                                None)[..., 0]
-            ckv_c = constrain(ckv_c, "batch", "kv_seq", "lora")
-            kr_c = constrain(kr_c, "batch", "kv_seq", "qk_dim")
+            lead = () if layer is None else ("layers",)
+            ckv_c = constrain(_write_cache(cache["ckv"], ckv, pos, 1, layer),
+                              *lead, "batch", "kv_seq", "lora")
+            kr_c = constrain(_write_cache(cache["krope"], krope, pos, 1, layer),
+                             *lead, "batch", "kv_seq", "qk_dim")
         with obs.scope(obs.ATTN_CORE):
             q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, wkv_b_k)
             ctx = _mla_two_pass(
-                q_abs, q_rope, ckv_c, kr_c, pos + 1, scale,
+                q_abs, q_rope, _layer(ckv_c, layer), _layer(kr_c, layer),
+                pos + 1, scale,
                 constraint=lambda s: constrain(s, "batch", None, "heads", "kv_seq"))
             out = jnp.einsum("bshr,rhv->bshv", ctx.astype(x.dtype), wkv_b_v)
         new_cache = {"ckv": ckv_c, "krope": kr_c}

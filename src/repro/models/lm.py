@@ -6,15 +6,17 @@ Modes:
   decode  — single-token step against the cache
 
 Uniform-block archs run layers through ``lax.scan`` over stacked params
-(remat per layer); the hybrid recurrentgemma runs an unrolled loop.
+(remat per layer; in decode the stacked cache is the scan's carry, written
+in place); the hybrid recurrentgemma runs an unrolled loop.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro import obs
-from repro.sharding import constrain, spec_for
+from repro.sharding import constrain, current, spec_for
 from repro.types import ArchConfig
 
 from .attention import gqa_block, mla_block
@@ -60,12 +62,13 @@ def cache_schema(cfg: ArchConfig, batch: int, max_len: int):
                     "krope": Param((batch, S, m.qk_rope_dim),
                                    ("batch", "kv_seq", "qk_dim"), "zeros"),
                 }
+            # heads-major, the layout of decode attention's contractions
             kh, hd = cfg.n_kv_heads, cfg.head_dim
             return {
-                "k": Param((batch, S, kh, hd),
-                           ("batch", "kv_seq", "kv_heads", "head_dim"), "zeros"),
-                "v": Param((batch, S, kh, hd),
-                           ("batch", "kv_seq", "kv_heads", "head_dim"), "zeros"),
+                "k": Param((batch, kh, S, hd),
+                           ("batch", "kv_heads", "kv_seq", "head_dim"), "zeros"),
+                "v": Param((batch, kh, S, hd),
+                           ("batch", "kv_heads", "kv_seq", "head_dim"), "zeros"),
             }
         if kind == "rglru":
             W = cfg.lru_width or cfg.d_model
@@ -127,14 +130,26 @@ def cache_specs(cfg, batch, max_len, rules):
 # Blocks
 # ---------------------------------------------------------------------------
 
-def _block_apply(kind, p, x, *, cfg, positions, mode, cache, pos):
+def _block_apply(kind, p, x, *, cfg, positions, mode, cache, pos, layer=None):
+    """One layer.  ``layer``: where ``cache`` is the stacked cache of every
+    layer (decode), this layer's index in it; the layer's new cache is then
+    the whole stack with this layer's entries written."""
+    if layer is not None and kind not in ("attn", "attn_local"):
+        # a recurrent state is replaced whole each step: this layer's is
+        # read out of the stack and written back whole
+        own = jax.tree.map(lambda a: a[layer], cache)
+        x, new = _block_apply(kind, p, x, cfg=cfg, positions=positions,
+                              mode=mode, cache=own, pos=pos)
+        return x, jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                a, n.astype(a.dtype), layer, 0), cache, new)
     if kind == "rwkv":
         return rwkv_block(p, x, cfg=cfg, mode=mode, cache=cache)
     if kind in ("attn", "attn_local"):
         window = cfg.local_window if kind == "attn_local" else None
         fn = mla_block if cfg.attn_kind == "mla" else gqa_block
         x, new_cache = fn(p, x, cfg=cfg, positions=positions, mode=mode,
-                          cache=cache, pos=pos, window=window)
+                          cache=cache, pos=pos, window=window, layer=layer)
     elif kind == "rglru":
         x, new_cache = rglru_block(p, x, cfg=cfg, mode=mode, cache=cache)
     else:
@@ -148,6 +163,24 @@ def _block_apply(kind, p, x, *, cfg, positions, mode, cache, pos):
             x = x + mlp_apply(mlp_p, rms_norm(x, p["ln2"]), cfg.mlp_kind)
     x = constrain(x, "batch", "seq", "embed")
     return x, new_cache
+
+
+def _in_place(layer_caches, cfg):
+    """The stacked layer caches pinned to the row-major layout they have as
+    the step's donated argument.  Left free, the compiler gives the loop's
+    carry the layout of its one-token update and relayouts both whole stacks
+    around the loop.  Under a mesh the pin runs per shard: the partitioner
+    would gather the whole cache for it."""
+    layouts = jax.tree.map(lambda a: Layout(tuple(range(a.ndim))),
+                           layer_caches)
+    pin = lambda t: with_layout_constraint(t, layouts)
+    ctx = current()
+    if ctx is None:
+        return pin(layer_caches)
+    mesh, rules = ctx
+    specs = cache_specs(cfg, 1, 1, rules)["layers"]
+    return jax.shard_map(pin, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                         check_vma=False)(layer_caches)
 
 
 def _run_stack(params, cfg, x, positions, mode, cache, remat="full",
@@ -194,6 +227,21 @@ def _run_layers(params, cfg, x, positions, mode, cache, remat, remat_group):
                 params["blocks"])
             x, _ = jax.lax.scan(_maybe_remat(group, remat), x, grouped)
             return x, None
+        if mode == "decode":
+            # The stacked cache is loop state, written in place at each
+            # layer's index: no layer of it is sliced out, restacked or
+            # copied, and the donated cache is the step's output.
+            def step(carry, xs):
+                h, lc = carry
+                lp, i = xs
+                h, lc = _block_apply(kind, lp, h, cfg=cfg, positions=positions,
+                                     mode=mode, cache=lc, pos=pos, layer=i)
+                return (h, _in_place(lc, cfg)), None
+
+            (x, layer_caches), _ = jax.lax.scan(
+                step, (x, layer_caches),
+                (params["blocks"], jnp.arange(cfg.n_layers)))
+            return x, layer_caches
         xs = (params["blocks"], layer_caches)
         x, new_layer_caches = jax.lax.scan(body, x, xs)
     else:

@@ -10,7 +10,8 @@ Where the ops of a scope land in a trace:
 
 - ``layers``: the whole layer stack (the ``lax.scan`` over stacked layers or
   the unrolled loop).  Its ops under no block scope are the scan's own
-  slicing of stacked params and caches and its stacking of outputs.
+  slicing of stacked params and caches and its stacking of outputs; decode
+  writes the stacked cache under ``attn/cache_write`` instead.
 - ``attn/qkv``, ``attn/cache_write``, ``attn/core``, ``attn/out``: an
   attention block's norm and projections, its cache update, the attention
   itself (kernel, decode attention, and the jnp backward's

@@ -74,10 +74,10 @@ def test_decode_attention_matches_full():
     k = jax.random.normal(ks[1], (B, S, KH, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, KH, D), jnp.float32)
     full = attention_reference(q_all, k, v, causal=True)
-    # cache padded beyond the valid length
-    pad = 24
-    kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    # heads-major cache, padded beyond the valid length
+    pad = ((0, 0), (0, 0), (0, 24), (0, 0))
+    kc = jnp.pad(k.transpose(0, 2, 1, 3), pad)
+    vc = jnp.pad(v.transpose(0, 2, 1, 3), pad)
     out = decode_attention(q_all[:, -1:], kc, vc, length=S)
     np.testing.assert_allclose(np.asarray(out[:, 0]),
                                np.asarray(full[:, -1]), atol=2e-6)

@@ -78,7 +78,8 @@ def test_prefill_decode_matches_forward(name):
                                atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.parametrize("name", ["recurrentgemma-2b", "rwkv6-7b", "yi-9b"])
+@pytest.mark.parametrize("name", ["minicpm3-4b", "recurrentgemma-2b",
+                                  "rwkv6-7b", "yi-9b"])
 def test_multi_token_decode_consistency(name):
     """Greedy decode step-by-step matches teacher-forced full forwards."""
     cfg = ARCHS[name].reduced()
@@ -100,6 +101,48 @@ def test_multi_token_decode_consistency(name):
                                    atol=3e-4, rtol=3e-4)
         cur = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
         seq = jnp.concatenate([seq, cur], axis=1)
+
+
+def test_decode_of_prefilled_rows_inserted_into_a_batch_cache():
+    """Caches prefilled a few rows at a time and inserted along the batch
+    axis (axis 1 of every cache leaf) into one batch cache decode as the
+    whole batch's teacher-forced forwards do."""
+    cfg = ARCHS["yi-9b"].reduced()
+    params = lm.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    B, G, S, M = 4, 2, 9, 32
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (B, S + 2), 0, cfg.vocab)
+    cache = lm.init_cache(cfg, B, M, jnp.float32)
+    for g in range(0, B, G):
+        _, part = lm.prefill(params, cfg, lm.init_cache(cfg, G, M, jnp.float32),
+                             tokens=tokens[g:g + G, :S])
+        cache = {"pos": part["pos"], "layers": jax.tree.map(
+            lambda big, small: jax.lax.dynamic_update_slice_in_dim(
+                big, small, g, axis=1), cache["layers"], part["layers"])}
+    for t in (S, S + 1):
+        logits, cache = lm.decode_step(params, cfg, cache, tokens[:, t:t + 1])
+        x, _ = lm.forward(params, cfg, tokens=tokens[:, :t + 1], mode="train",
+                          remat="none")
+        ref = jnp.einsum("bd,dv->bv", x[:, -1], params["lm_head"])
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
+                                   atol=3e-4, rtol=3e-4)
+
+
+def test_decode_step_keeps_one_stacked_cache():
+    """The donated stacked cache is the decode step's only copy of it: the
+    step writes each layer's token in place and stacks no second cache
+    (e.g. as a layer loop's per-layer outputs)."""
+    import dataclasses
+    cfg = dataclasses.replace(ARCHS["yi-9b"].reduced(), n_layers=4)
+    params = lm.abstract_params(cfg, jnp.float32)
+    cache = lm.abstract_cache(cfg, 2, 32768, jnp.float32)
+    tokens = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    mem = jax.jit(lambda p, c, t: lm.decode_step(p, cfg, c, t),
+                  donate_argnums=1).lower(params, cache, tokens).compile(
+                  ).memory_analysis()
+    stack = sum(a.size * a.dtype.itemsize
+                for a in jax.tree.leaves(cache["layers"]))
+    assert mem.alias_size_in_bytes >= stack
+    assert mem.temp_size_in_bytes < stack / 2, (mem.temp_size_in_bytes, stack)
 
 
 def test_local_attention_window_ring_buffer():

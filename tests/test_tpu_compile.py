@@ -110,3 +110,29 @@ def test_rglru_scan_compiles(one_chip, no_compile_cache):
     h0 = jax.ShapeDtypeStruct((2, 2560), jnp.float32, sharding=one_chip)
     _compile_for_chip(
         lambda a, b, h0: rglru_scan(a, b, h0, backend="pallas"), a, a, h0)
+
+
+def test_decode_step_writes_the_cache_in_place(one_chip, no_compile_cache):
+    """yi-9b decode at the widths and cache of the benchmark's decode cell
+    (2 of its 8 layers): with the cache donated, the chip's program holds no
+    temporary as large as one layer's keys and values, so no layer of the
+    stacked cache is sliced out, relaid out or stacked again."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import lm
+
+    cfg = dataclasses.replace(get_config("yi-9b"), n_layers=2)
+    on_chip = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    params = on_chip(lm.abstract_params(cfg, jnp.bfloat16))
+    cache = on_chip(lm.abstract_cache(cfg, 8, 32768))
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    mem = jax.jit(lambda p, c, t: lm.decode_step(p, cfg, c, t),
+                  donate_argnums=1).lower(params, cache, tokens).compile(
+                  ).memory_analysis()
+    stack = sum(a.size * a.dtype.itemsize
+                for a in jax.tree.leaves(cache["layers"]))
+    assert mem.alias_size_in_bytes >= stack
+    assert mem.temp_size_in_bytes < stack / cfg.n_layers, (
+        mem.temp_size_in_bytes, stack)
