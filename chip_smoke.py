@@ -238,24 +238,15 @@ def phase_serve(seed, batch=16, prompt=8192, decode_steps=32):
 
 
 def phase_serve_four_chips(seed, devs, batch=8, prompt=4096):
-    from jax.sharding import NamedSharding
-
     from repro.launch.mesh import make_chip_mesh
     from repro.models import lm
-    from repro.sharding import make_rules, use_mesh_rules
-    from repro.train.steps import batch_specs
-    from repro.types import ShapeConfig
+    from repro.train.steps import make_sharded_serve
 
     cfg = yi(YI_LAYERS_SERVE)
     max_len = prompt + 1
-    mesh = make_chip_mesh(4)
-    rules = make_rules(cfg, mesh, global_batch=batch)
-    shard = lambda specs: jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
-    p_sh = shard(lm.param_specs(cfg, rules))
-    c_sh = shard(lm.cache_specs(cfg, batch, max_len, rules))
-    tok_sh = shard(batch_specs(cfg, ShapeConfig("smoke", prompt, batch,
-                                                "prefill"), rules))["tokens"]
-    print(f"[serve4] mesh {dict(mesh.shape)} heads->{rules['heads']} "
+    serve = make_sharded_serve(cfg, make_chip_mesh(4), batch, max_len)
+    rules = serve.rules
+    print(f"[serve4] mesh {dict(serve.mesh.shape)} heads->{rules['heads']} "
           f"kv_heads->{rules['kv_heads']} ffn->{rules['ffn']} "
           f"vocab->{rules['vocab']}", flush=True)
 
@@ -275,29 +266,23 @@ def phase_serve_four_chips(seed, devs, batch=8, prompt=4096):
         ref_dec, _ = jax.jit(decode_fn)(params1, ref_cache, ref_next)
         ref_dec.block_until_ready()
     del ref_cache
-    params4 = jax.device_put(params1, p_sh)
+    params4 = jax.device_put(params1, serve.params)
     del params1
     gc.collect()
 
-    replicated = NamedSharding(mesh, jax.sharding.PartitionSpec())
-    tokens4 = jax.device_put(tokens, tok_sh)
-    next4 = jax.device_put(ref_next, tok_sh)
-    with use_mesh_rules(mesh, rules):
-        prefill4 = jax.jit(prefill_fn, in_shardings=(p_sh, tok_sh),
-                           out_shardings=(replicated, c_sh))
-        with step("serve4: sharded prefill compile"):
-            compiled = prefill4.lower(params4, tokens4).compile()
-        decode4 = jax.jit(decode_fn, in_shardings=(p_sh, c_sh, tok_sh),
-                          out_shardings=(replicated, c_sh))
-        hlo = compiled.as_text()
-        check("tpu_custom_call" in hlo, "sharded prefill holds no Pallas kernel")
-        print(f"[serve4] sharded prefill: all-gather ops={hlo.count('all-gather(')} "
-              f"all-reduce ops={hlo.count('all-reduce(')}; memory_analysis "
-              f"{compiled.memory_analysis()}", flush=True)
-        with step("serve4: sharded prefill run + decode (compile + run)"):
-            logits4, cache4 = compiled(params4, tokens4)
-            dec4, _ = decode4(params4, cache4, next4)
-            dec4.block_until_ready()
+    tokens4 = jax.device_put(tokens, serve.tokens)
+    next4 = jax.device_put(ref_next, serve.tokens)
+    with step("serve4: sharded prefill compile"):
+        compiled = serve.prefill.lower(params4, tokens4).compile()
+    hlo = compiled.as_text()
+    check("tpu_custom_call" in hlo, "sharded prefill holds no Pallas kernel")
+    print(f"[serve4] sharded prefill: all-gather ops={hlo.count('all-gather(')} "
+          f"all-reduce ops={hlo.count('all-reduce(')}; memory_analysis "
+          f"{compiled.memory_analysis()}", flush=True)
+    with step("serve4: sharded prefill run + decode (compile + run)"):
+        logits4, cache4 = compiled(params4, tokens4)
+        dec4, _ = serve.decode(params4, cache4, next4)
+        dec4.block_until_ready()
     e_pre, e_dec = rel_l2(logits4, ref_logits), rel_l2(dec4, ref_dec)
     print(f"[serve4] 4 chips vs 1 chip logits (bf16): prefill rel_l2={e_pre} "
           f"decode rel_l2={e_dec} tol rel_l2={LOGITS_REL_L2}", flush=True)

@@ -335,6 +335,18 @@ def prefill(params, cfg: ArchConfig, cache, *, tokens=None, embeds=None):
         return logits[:, 0], new_cache
 
 
+def insert_rows(cfg: ArchConfig, cache, part, row):
+    """``cache`` with the sequences of ``part``, a cache of fewer of them
+    (a prefill of a few), written from batch row ``row`` on in ``cache``'s
+    dtype; the position is ``part``'s."""
+    axis = 1 if cfg.uniform_blocks else 0  # stacked leaves lead with layers
+    layers = jax.tree.map(
+        lambda big, small: jax.lax.dynamic_update_slice_in_dim(
+            big, small.astype(big.dtype), row, axis=axis),
+        cache["layers"], part["layers"])
+    return {"pos": part["pos"], "layers": layers}
+
+
 def decode_step(params, cfg: ArchConfig, cache, tokens):
     """One decode step.  tokens: (B, 1) int32.  Returns (logits, cache)."""
     x, new_cache = forward(params, cfg, tokens=tokens, mode="decode",

@@ -115,9 +115,7 @@ def test_decode_of_prefilled_rows_inserted_into_a_batch_cache():
     for g in range(0, B, G):
         _, part = lm.prefill(params, cfg, lm.init_cache(cfg, G, M, jnp.float32),
                              tokens=tokens[g:g + G, :S])
-        cache = {"pos": part["pos"], "layers": jax.tree.map(
-            lambda big, small: jax.lax.dynamic_update_slice_in_dim(
-                big, small, g, axis=1), cache["layers"], part["layers"])}
+        cache = lm.insert_rows(cfg, cache, part, g)
     for t in (S, S + 1):
         logits, cache = lm.decode_step(params, cfg, cache, tokens[:, t:t + 1])
         x, _ = lm.forward(params, cfg, tokens=tokens[:, :t + 1], mode="train",

@@ -136,3 +136,51 @@ def test_decode_step_writes_the_cache_in_place(one_chip, no_compile_cache):
     assert mem.alias_size_in_bytes >= stack
     assert mem.temp_size_in_bytes < stack / cfg.n_layers, (
         mem.temp_size_in_bytes, stack)
+
+
+def _collective_bytes(hlo, op):
+    """Bytes of each ``op`` instruction's result in an HLO text."""
+    import re
+    sizes = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1}
+    out = []
+    for dtype, dims in re.findall(
+            rf"= \(?(\w+)\[([\d,]*)\][^=]*? {op}(?:-start)?\(", hlo):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        out.append(n * sizes[dtype])
+    return out
+
+
+def test_tp4_decode_step_keeps_the_sharded_cache_in_place(topo, no_compile_cache):
+    """yi-9b decode tensor-parallel over the four chips of a v5e 2x2, as the
+    benchmark's four-chip cell serves it (2 of its 48 layers, batch 8, a
+    32768-position cache, donated): each chip holds no temporary as large as
+    its share of one layer's cache, and no collective gathers a cache."""
+    import dataclasses
+
+    import numpy as np
+
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.train.steps import make_sharded_serve
+
+    cfg = dataclasses.replace(get_config("yi-9b"), n_layers=2)
+    mesh = jax.sharding.Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    serve = make_sharded_serve(cfg, mesh, 8, 32768)
+    placed = lambda t, sh: jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), t, sh)
+    params = placed(lm.abstract_params(cfg, jnp.bfloat16), serve.params)
+    cache = placed(lm.abstract_cache(cfg, 8, 32768), serve.cache)
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=serve.tokens)
+    compiled = serve.decode.lower(params, cache, tokens).compile()
+    mem = compiled.memory_analysis()
+    chip_stack = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(cache["layers"])) // 4
+    chip_layer = chip_stack // cfg.n_layers
+    assert mem.alias_size_in_bytes >= chip_stack
+    assert mem.temp_size_in_bytes < chip_layer, (mem.temp_size_in_bytes, chip_layer)
+    hlo = compiled.as_text()
+    assert len(_collective_bytes(hlo, "all-reduce")) > 0
+    gathers = _collective_bytes(hlo, "all-gather")
+    assert all(n < chip_layer for n in gathers), gathers
