@@ -62,10 +62,13 @@ def _yi_qkv(sharding, S):
     return q, kv, kv
 
 
-def test_flash_attention_forward_compiles(one_chip, no_compile_cache):
+# at 131072 the kernel's tile schedule, 32896 words (one per visited causal
+# tile at 512 blocks), must still fit the chip's scalar memory
+@pytest.mark.parametrize("S", [32768, 131072])
+def test_flash_attention_forward_compiles(one_chip, no_compile_cache, S):
     _compile_for_chip(
         lambda q, k, v: flash_attention(q, k, v, backend="pallas"),
-        *_yi_qkv(one_chip, 32768))
+        *_yi_qkv(one_chip, S))
 
 
 def test_flash_attention_grad_compiles(one_chip, no_compile_cache):
