@@ -37,19 +37,8 @@ def archs():
     return list(ARCHS.values())
 
 
-def comm_model(calibrate: bool = False) -> CommModel:
-    """calibrate=True rescales per-arch gradient volume from the compiled
-    dry-run artifacts.  Off by default for the scheduler benchmarks: the
-    dry-run measures a 256-chip DP x TP x EP training step whose collective
-    mix (TP activations, EP dispatch, remat re-reduction) is not the pure
-    data-parallel gradient ring of the simulated 1-64 GPU jobs; using it
-    inflates MoE sensitivities by the clamp ceiling.  See EXPERIMENTS.md."""
-    cm = CommModel.from_configs(archs())
-    if calibrate:
-        d = ART / "dryrun" / "baseline"
-        if d.exists():
-            cm.load_calibration(str(d))
-    return cm
+def comm_model() -> CommModel:
+    return CommModel.from_configs(archs())
 
 
 _SIM_CACHE = {}

@@ -8,7 +8,7 @@ import time
 from . import (fig7_makespan, fig8_tails, fig9_jct_cdf, fig10_poisson,
                fig11_utilization, fig12_contention, fig13_parallelism,
                fig14_scale, fig15_failures, fig16_degradation,
-               roofline_report, table1_comm_latency, table2_jct_stats)
+               table1_comm_latency, table2_jct_stats)
 
 ALL = [
     ("table1_comm_latency", table1_comm_latency.main),
@@ -23,7 +23,6 @@ ALL = [
     ("fig14_scale", fig14_scale.main),
     ("fig15_failures", fig15_failures.main),
     ("fig16_degradation", fig16_degradation.main),
-    ("roofline_report", roofline_report.main),
 ]
 
 

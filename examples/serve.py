@@ -1,5 +1,5 @@
 """Batched serving example: prefill a batch of prompts, then decode with a
-shared KV cache — the serve_step lowered by decode_* dry-run cells.
+shared KV cache (``lm.prefill``, then ``lm.decode_step`` once per token).
 
     PYTHONPATH=src python examples/serve.py [--arch rwkv6-7b]
 """

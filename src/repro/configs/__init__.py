@@ -1,9 +1,9 @@
-"""Registry of the assigned architectures and input shapes.
+"""Registry of the assigned architectures.
 
 Every architecture is selectable via ``--arch <id>`` in the launchers; ids use
 dashes exactly as assigned.
 """
-from repro.types import SHAPES, ArchConfig, ShapeConfig, applicable  # noqa: F401
+from repro.types import ArchConfig
 
 from . import (
     hubert_xlarge,
@@ -39,17 +39,3 @@ def get_config(name: str) -> ArchConfig:
     if name not in ARCHS:
         raise KeyError(f"unknown arch {name!r}; available: {sorted(ARCHS)}")
     return ARCHS[name]
-
-
-def get_shape(name: str) -> ShapeConfig:
-    if name not in SHAPES:
-        raise KeyError(f"unknown shape {name!r}; available: {sorted(SHAPES)}")
-    return SHAPES[name]
-
-
-def all_cells():
-    """Yield (arch, shape, runnable, reason) for the full 40-cell matrix."""
-    for a in ARCHS.values():
-        for s in SHAPES.values():
-            ok, why = applicable(a, s)
-            yield a, s, ok, why

@@ -2,8 +2,8 @@
 
 [arXiv:2106.07447; unverified]  48L d_model=1280 16H (kv=16) d_ff=5120
 vocab=504 (cluster targets).  Bidirectional attention, GELU MLP, no decode
-step.  The audio frontend (conv feature extractor) is a stub: input_specs()
-feeds precomputed frame embeddings (B, n_frames, d_model).
+step.  The audio frontend (conv feature extractor) is a stub: the batch
+carries precomputed frame embeddings (B, n_frames, d_model).
 """
 from repro.types import ArchConfig
 

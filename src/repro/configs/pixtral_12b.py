@@ -1,7 +1,7 @@
 """pixtral-12b — pixtral-ViT frontend (stub) + mistral-nemo decoder backbone.
 
 [hf:mistralai/Pixtral-12B-2409; unverified]  40L d_model=5120 32H (GQA kv=8)
-d_ff=14336 vocab=131072.  The vision frontend is a stub: input_specs() feeds
+d_ff=14336 vocab=131072.  The vision frontend is a stub: the batch carries
 precomputed patch embeddings (B, n_patches, d_model) to the backbone.
 """
 from repro.types import ArchConfig

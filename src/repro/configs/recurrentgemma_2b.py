@@ -21,6 +21,5 @@ CONFIG = ArchConfig(
     lru_width=2560,
     rope_theta=10_000.0,
     tie_embeddings=True,
-    subquadratic=True,
     source="[arXiv:2402.19427; hf]",
 )

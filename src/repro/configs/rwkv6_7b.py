@@ -19,6 +19,5 @@ CONFIG = ArchConfig(
     attn_kind="none",
     mlp_kind="relu2",
     rwkv_head_dim=64,
-    subquadratic=True,
     source="[arXiv:2404.05892; hf]",
 )

@@ -3,7 +3,7 @@
 Components (paper §IV):
   topology    — hierarchical cluster (machine / rack / network tiers)
   commmodel   — per-placement communication latency (ASTRA-sim analogue,
-                calibrated against this repo's compiled dry-run collectives)
+                analytic, with an optional per-arch calibration factor)
   parallelism — per-job hybrid DP/TP/PP/EP plans: per-pattern collective
                 traffic (ring / all-gather / point-to-point / all-to-all)
   fabric      — shared rack-uplink/spine fabric: cross-job fair-share

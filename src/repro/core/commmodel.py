@@ -9,9 +9,10 @@ network tier the placement spans, minus the compute it overlaps with:
   exposed = max(0, T_comm - overlap_frac * T_compute)
 
 M (gradient bytes) and n_buckets (layers) come from the real architecture
-configs; an optional calibration factor per arch is derived from the compiled
-dry-run artifacts (measured collective bytes / analytic bytes), mirroring the
-paper's <1% calibration of ASTRA-sim workload files against real runs.
+configs.  An optional per-arch calibration factor (measured collective bytes /
+analytic bytes, given to the constructor) scales the byte volume, as the
+paper calibrates ASTRA-sim workload files against real runs; without one the
+model is purely analytic.
 
 Jobs carrying a hybrid :class:`~repro.core.parallelism.ParallelPlan` are
 priced by ``plan_time`` instead: a composition of per-pattern collective
@@ -23,8 +24,6 @@ bit-for-bit, so plan-less workloads reproduce the legacy numbers.
 """
 from __future__ import annotations
 
-import json
-import pathlib
 from typing import Dict, Optional
 
 from repro.types import HardwareProfile, TPU_V5E
@@ -70,29 +69,6 @@ class CommModel:
             table[cfg.name] = {"params": cfg.n_params(),
                                "layers": cfg.n_layers}
         return cls(table, **kw)
-
-    def load_calibration(self, artifact_dir: str, shape: str = "train_4k",
-                         mesh: str = "pod16x16"):
-        """Calibrate per-arch gradient volume against the compiled dry-run:
-        factor = measured collective bytes / analytic ring all-reduce bytes.
-        Mirrors ArtISt-sim's calibration of ASTRA-sim workload files."""
-        d = pathlib.Path(artifact_dir)
-        for name in self.arch_table:
-            f = d / f"{name}__{shape}__{mesh}.json"
-            if not f.exists():
-                continue
-            rec = json.loads(f.read_text())
-            if rec.get("status") != "ok":
-                continue
-            measured = rec["hlo"]["collective_bytes"]
-            grad = self.arch_table[name]["params"] * self.grad_dtype_bytes
-            if grad > 0 and measured > 0:
-                # per-device measured vs 2M/n analytic per device
-                n = rec.get("n_chips", 256)
-                analytic = 2.0 * grad / n
-                self.calibration[name] = min(max(measured / analytic, 0.1),
-                                             50.0)
-        self._ar_cache.clear()  # calibration changes the cached latencies
 
     # -- core latency model ---------------------------------------------
     def _ring(self, bytes_, n, tier_name, n_buckets, bw_override=None):

@@ -18,9 +18,8 @@ shared fabric very differently:
   group size and the token volume does not reduce).
 
 A :class:`ParallelPlan` is pure data: the four degrees plus per-iteration
-byte volumes, derivable from the architecture configs (``plan_for``) and
-optionally calibrated against the compiled dry-run's collective-bytes-by-
-group-size breakdown (``launch/hlo_analysis``).  ``CommModel.plan_time``
+byte volumes, derivable from the architecture configs (``plan_for``); a
+per-arch ``CommModel`` calibration factor scales them.  ``CommModel.plan_time``
 composes the per-pattern costs; a *degenerate* plan (dp=n, tp=pp=ep=1)
 routes through the exact pure-DP code path, bit-for-bit.
 """

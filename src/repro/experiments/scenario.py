@@ -254,7 +254,7 @@ class Scenario:
         return FairShareFabric(cluster, nic_bw=nic_bw,
                                rack_uplink_bw=uplink, spine_bw=spine)
 
-    def build_comm(self, archs, calibration=None) -> CommModel:
+    def build_comm(self, archs) -> CommModel:
         profile = PROFILES[self.profile]
         if self.bandwidth_scale:
             # contended network regime: scale per-tier usable bandwidth
@@ -264,8 +264,7 @@ class Scenario:
                 for t in profile.tiers)
             profile = dataclasses.replace(profile, tiers=tiers)
         return CommModel.from_configs(archs, profile=profile,
-                                      overlap_frac=self.overlap_frac,
-                                      calibration=calibration)
+                                      overlap_frac=self.overlap_frac)
 
     def build_failures(self, machine_ids, seed: int):
         """The cell's failure schedule, or None when churn is off.

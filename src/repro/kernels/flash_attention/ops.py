@@ -2,7 +2,7 @@
 
 * ``flash_attention`` — public entry point; dispatches to the Pallas TPU kernel
   on TPU backends and to ``chunked_attention`` (pure jnp, memory-bounded,
-  GSPMD-friendly) elsewhere (CPU smoke tests and the 512-device dry-run).
+  GSPMD-friendly) elsewhere (the CPU tests).
   The Pallas path differentiates through the VJP of ``chunked_attention``
   (``flash_attention_bwd_chunked_jnp``) and, under a mesh that shards heads,
   runs per head shard in a ``shard_map``.

@@ -1,6 +1,7 @@
 """Parameter schema: single source of truth for shapes, logical sharding axes
 and initializers.  From one schema tree we derive (a) real initialized params,
-(b) ShapeDtypeStruct abstract params for the dry-run, (c) PartitionSpecs.
+(b) ShapeDtypeStruct abstract params for compiling without weights, (c)
+PartitionSpecs.
 """
 from __future__ import annotations
 

@@ -1,18 +1,12 @@
 """AdamW with f32 master weights, global-norm clipping and a cosine schedule.
 
 Train state is a plain dict pytree:
-  {"params": bf16 compute params, "master"/"mu"/"nu": f32 (ZeRO-1 sharded),
-   "step": scalar}
-
-ZeRO-1: optimizer leaves get one extra data-parallel partition on the first
-dimension that is unsharded and divisible by the DP world size — XLA then
-materializes the reduce-scatter(grads) / all-gather(params) pattern.
+  {"params": bf16 compute params, "master"/"mu"/"nu": f32, "step": scalar}
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro import obs
 
@@ -84,41 +78,3 @@ def _adamw_update(state, grads, *, lr, b1, b2, eps, weight_decay, clip):
         lambda m, p: m.astype(p.dtype), master, state["params"])
     return {"params": params, "master": master, "mu": mu, "nu": nu,
             "step": step}, {"grad_norm": gnorm}
-
-
-# ---------------------------------------------------------------------------
-# sharding specs
-# ---------------------------------------------------------------------------
-
-def _zero1(spec: P, shape, dp_axes, mesh) -> P:
-    """Add a DP partition on the first unsharded, divisible dim."""
-    if dp_axes is None:
-        return spec
-    dp_size = 1
-    for a in dp_axes:
-        dp_size *= mesh.shape[a]
-    parts = list(spec) + [None] * (len(shape) - len(spec))
-    for i, (p, s) in enumerate(zip(parts, shape)):
-        if p is None and s % dp_size == 0 and s > 0:
-            parts[i] = dp_axes if len(dp_axes) > 1 else dp_axes[0]
-            return P(*parts)
-    return spec
-
-
-def train_state_specs(param_spec_tree, abstract_param_tree, mesh, rules):
-    """Build PartitionSpecs for the full train state (ZeRO-1 optimizer)."""
-    dp = rules.get("batch")
-    dp_axes = (dp,) if isinstance(dp, str) else dp
-
-    def z(spec, aparam):
-        return _zero1(spec, aparam.shape, dp_axes, mesh)
-
-    opt_spec = jax.tree.map(z, param_spec_tree, abstract_param_tree,
-                            is_leaf=lambda x: isinstance(x, P))
-    return {
-        "params": param_spec_tree,
-        "master": opt_spec,
-        "mu": opt_spec,
-        "nu": opt_spec,
-        "step": P(),
-    }

@@ -1,9 +1,10 @@
-"""Logical-axis sharding rules (MaxText-style) for the production mesh.
+"""Logical-axis sharding rules (MaxText-style) for a ("data", "model") mesh.
 
 Models annotate params/activations with *logical* axis names; a per-arch rule
-table maps those to mesh axes.  The mapping accounts for the hard constraints
-of the assigned 16-way "model" axis (head counts that do not divide 16 fall
-back to replication; see DESIGN.md §4).
+table maps those to mesh axes.  An FFN width or vocabulary that does not
+divide the "model" axis is replicated; kv heads that do not divide it leave
+the decode cache sharded on its sequence instead; query heads and experts
+shard anyway, and GSPMD pads them.
 
 ``use_mesh_rules`` installs a (mesh, rules) context so deep model code can call
 ``constrain(x, *logical)`` without threading the mesh everywhere; outside the
@@ -31,14 +32,11 @@ def _prod(mesh, axes):
     return n
 
 
-def make_rules(cfg, mesh, *, seq_shard: bool = False,
-               global_batch: Optional[int] = None) -> Rules:
+def make_rules(cfg, mesh, *, global_batch: Optional[int] = None) -> Rules:
     """Build the logical->mesh mapping for one architecture on one mesh.
 
-    seq_shard: also shard activation *sequence* dims over "model" (sequence
-    parallelism; a §Perf hillclimb option, off in the baseline).
     global_batch: if given and not divisible by the DP world size, the batch
-    axis is replicated (e.g. long_500k has global_batch=1).
+    axis is replicated (e.g. a batch of one).
     """
     names = mesh.axis_names
     dp = tuple(a for a in ("pod", "data") if a in names)
@@ -62,7 +60,7 @@ def make_rules(cfg, mesh, *, seq_shard: bool = False,
         and cfg.attn_kind == "gqa"
     rules: Rules = {
         "batch": dp,
-        "seq": tp if (seq_shard and tp) else None,
+        "seq": None,
         # decode cache: shard kv heads when they divide the model axis,
         # otherwise shard the cache *sequence* dim (flash-decoding in SPMD)
         "kv_seq": None if kv_heads_shardable else tp,

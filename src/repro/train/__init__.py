@@ -1,8 +1,5 @@
 from .steps import (  # noqa: F401
-    batch_specs,
-    input_specs,
     make_decode_step,
     make_prefill_step,
     make_train_step,
-    useful_flops,
 )

@@ -1,8 +1,8 @@
 """Core dataclasses shared by every layer of the framework.
 
-ArchConfig describes one of the assigned architectures; ShapeConfig one of the
-assigned input shapes; HardwareProfile the accelerator + network constants used
-by both the roofline analysis and the cluster simulator's communication model.
+ArchConfig describes one of the assigned architectures; HardwareProfile the
+accelerator and network constants of the cluster simulator's compute and
+communication models.
 """
 from __future__ import annotations
 
@@ -63,7 +63,6 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
     has_decoder: bool = True        # False for encoder-only (hubert)
-    subquadratic: bool = False      # can run long_500k decode
     frontend: Optional[str] = None  # None | "audio" | "vision" (stub embeddings)
     rwkv_head_dim: int = 64
     lru_width: Optional[int] = None  # RG-LRU recurrence width (defaults d_model)
@@ -239,37 +238,7 @@ class ArchConfig:
 
 
 # ---------------------------------------------------------------------------
-# Input shapes
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
-
-def applicable(arch: ArchConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """Whether the (arch, shape) cell is architecturally runnable."""
-    if shape.kind == "decode" and not arch.has_decoder:
-        return False, "encoder-only arch has no decode step"
-    if shape.name == "long_500k" and not arch.subquadratic:
-        return False, "pure full-attention arch; 500k dense decode is quadratic (skip per assignment)"
-    return True, ""
-
-
-# ---------------------------------------------------------------------------
-# Hardware profiles (roofline + simulator communication model)
+# Hardware profiles (simulator compute and communication models)
 # ---------------------------------------------------------------------------
 
 
@@ -284,9 +253,6 @@ class NetworkTier:
 class HardwareProfile:
     name: str
     peak_flops: float          # per chip, bf16
-    hbm_bw: float              # bytes/s per chip
-    link_bw: float             # bytes/s per ICI link (roofline collective term)
-    hbm_per_chip: float        # bytes
     accel_per_machine: int
     machines_per_rack: int
     tiers: Tuple[NetworkTier, ...]  # ordered best -> worst
@@ -302,9 +268,6 @@ class HardwareProfile:
 TPU_V5E = HardwareProfile(
     name="tpu_v5e",
     peak_flops=197e12,
-    hbm_bw=819e9,
-    link_bw=50e9,
-    hbm_per_chip=16e9,
     accel_per_machine=8,
     machines_per_rack=8,
     tiers=(
@@ -318,9 +281,6 @@ TPU_V5E = HardwareProfile(
 NVIDIA_PAPER = HardwareProfile(
     name="nvidia_paper",
     peak_flops=312e12,          # A100-class bf16
-    hbm_bw=2039e9,
-    link_bw=112.5e9,            # 900 Gb/s NVSwitch per-GPU
-    hbm_per_chip=80e9,
     accel_per_machine=8,
     machines_per_rack=8,
     tiers=(

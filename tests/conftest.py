@@ -18,8 +18,8 @@ except ImportError:
 
     hypothesis_fallback.install()
 
-# Tests must see exactly ONE device (the dry-run sets its own 512-device flag
-# in a separate process); keep any ambient XLA_FLAGS from leaking in.
+# Tests must see exactly ONE device (tests that need a mesh force host
+# devices in a child process); keep any ambient XLA_FLAGS from leaking in.
 os.environ.pop("XLA_FLAGS", None)
 
 import jax  # noqa: E402

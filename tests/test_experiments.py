@@ -327,14 +327,30 @@ def test_comm_cache_matches_uncached():
     assert cached.cache_hits > 0 and uncached.cache_hits == 0
 
 
-def test_comm_cache_invalidated_by_calibration(tmp_path):
-    cm = CommModel.from_configs(ARCHS_L)
-    pl = Placement(((0, 4), (1, 4)))
-    before = cm.allreduce_time("yi-9b", pl, 8, 8)
-    (tmp_path / "yi-9b__train_4k__pod16x16.json").write_text(json.dumps({
-        "status": "ok", "n_chips": 256,
-        "hlo": {"collective_bytes": 4.0 * 2 * ARCHS["yi-9b"].n_params() / 256},
-    }))
-    cm.load_calibration(str(tmp_path))
-    after = cm.allreduce_time("yi-9b", pl, 8, 8)
-    assert after != before  # stale cached value must not survive
+def test_comm_cache_invalidated_by_calibration():
+    """A calibration given at construction reaches the memoized latencies:
+    cached equals a cache-less twin with and without one, and only the
+    calibrated arch's cross-rack latency moves."""
+    cal = {"yi-9b": 2.0}
+    plain = CommModel.from_configs(ARCHS_L)
+    scaled = CommModel.from_configs(ARCHS_L, calibration=cal)
+    twins = ((plain, CommModel.from_configs(ARCHS_L, cache_size=0)),
+             (scaled, CommModel.from_configs(ARCHS_L, calibration=cal,
+                                             cache_size=0)))
+    placements = [Placement(((0, 4), (1, 4))), Placement(((0, 8),)),
+                  Placement(((0, 4), (9, 4)))]  # machine 9 is in rack 1
+    for _ in range(2):  # the second pass reads the memo
+        for cached, uncached in twins:
+            for name in ("yi-9b", "qwen3-1.7b"):
+                for pl in placements:
+                    assert (cached.allreduce_time(name, pl, 8, 8)
+                            == uncached.allreduce_time(name, pl, 8, 8))
+                    assert (cached.iteration_time(name, 0.5, pl, 8, 8)
+                            == uncached.iteration_time(name, 0.5, pl, 8, 8))
+    assert plain.cache_hits > 0 and scaled.cache_hits > 0
+    cross = placements[2]
+    assert cross.tier(8) == "network"
+    assert (scaled.allreduce_time("yi-9b", cross, 8, 8)
+            > plain.allreduce_time("yi-9b", cross, 8, 8))
+    assert (scaled.allreduce_time("qwen3-1.7b", cross, 8, 8)
+            == plain.allreduce_time("qwen3-1.7b", cross, 8, 8))
