@@ -3,9 +3,9 @@
 * ``flash_attention`` — public entry point; dispatches to the Pallas TPU kernel
   on TPU backends and to ``chunked_attention`` (pure jnp, memory-bounded,
   GSPMD-friendly) elsewhere (the CPU tests).
-  The Pallas path differentiates through the VJP of ``chunked_attention``
-  (``flash_attention_bwd_chunked_jnp``) and, under a mesh that shards heads,
-  runs per head shard in a ``shard_map``.
+  The Pallas path differentiates through the Pallas backward kernels
+  (``flash_attention_bwd_dkv``, ``flash_attention_bwd_dq``) and, under a
+  mesh that shards heads, runs per head shard in a ``shard_map``.
 * ``chunked_attention`` — scan-of-scans online softmax, O(seq * chunk) memory.
 * ``decode_attention`` — single-token two-pass softmax written so that a KV
   cache whose *sequence* dim is sharded over the "model" mesh axis lowers to
@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.sharding import current
 
-from .kernel import flash_attention_kernel
+from .kernel import flash_attention_bwd_kernel, flash_attention_kernel
 
 NEG_INF = -1e30
 
@@ -40,14 +40,12 @@ def _pad_to(x, axis, mult):
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "window", "q_offset", "kv_len", "q_chunk",
-                     "k_chunk", "remat"))
+                     "k_chunk"))
 def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
-                      kv_len=None, q_chunk=512, k_chunk=512, remat=False):
+                      kv_len=None, q_chunk=512, k_chunk=512):
     """Online-softmax attention via lax.scan over (q chunks × kv chunks).
 
     q: (B, Sq, H, D); k, v: (B, Sk, KH, D).  Returns (B, Sq, H, D).
-    ``remat``: checkpoint every q chunk and kv step, so the VJP keeps
-    O(S·chunk) residuals instead of one (chunk × chunk) tile per step.
     """
     B, Sq, H, D = q.shape
     _, Sk, KH, _ = k.shape
@@ -104,8 +102,6 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
                 "bqhk,bkhd->bqhd", p, vb, preferred_element_type=jnp.float32)
             return (m_new, l_new, acc_new), None
 
-        if remat:
-            kv_block = jax.checkpoint(kv_block)
         init = (
             jnp.full((B, q_chunk, H), NEG_INF, jnp.float32),
             jnp.zeros((B, q_chunk, H), jnp.float32),
@@ -116,8 +112,6 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
         out = jnp.where((l > 0.0)[..., None], acc / safe[..., None], 0.0)
         return None, out.astype(q.dtype)
 
-    if remat:
-        q_block = jax.checkpoint(q_block)
     _, outs = jax.lax.scan(q_block, None, (qs, jnp.arange(nq)))
     out = outs.transpose(1, 0, 2, 3, 4).reshape(B, nq * q_chunk, H, Dv)
     return out[:, :Sq]
@@ -167,21 +161,32 @@ def _round_up(n, m):
     return -(-n // m) * m
 
 
-def _flash_forward(q, k, v, o: _FlashOpts):
-    """Pallas forward in the models' (B, S, H, D) layout: transpose to the
-    kernel's heads-major layout, pad the sequences to the block, mask the
-    padded keys through ``kv_len``, and slice the padding back off."""
+def _blocked(q, k, v, o: _FlashOpts):
+    """The kernels' heads-major layout of the models' (B, S, H, D) inputs,
+    sequences padded to the block, and the kernels' static arguments (the
+    padded keys masked through ``kv_len``)."""
     Sq, Sk = q.shape[1], k.shape[1]
     bq = min(o.block_q, _round_up(Sq, 128))
     bk = min(o.block_k, _round_up(Sk, 128))
     qh = _pad_to(q.transpose(0, 2, 1, 3), 2, bq)
     kh = _pad_to(k.transpose(0, 2, 1, 3), 2, bk)
     vh = _pad_to(v.transpose(0, 2, 1, 3), 2, bk)
-    out = flash_attention_kernel(
-        qh, kh, vh, causal=o.causal, window=o.window, q_offset=o.q_offset,
-        kv_len=Sk if o.kv_len is None else o.kv_len, block_q=bq, block_k=bk,
-        interpret=o.interpret)
-    return out[:, :, :Sq].transpose(0, 2, 1, 3)
+    kw = dict(causal=o.causal, window=o.window, q_offset=o.q_offset,
+              kv_len=Sk if o.kv_len is None else o.kv_len, block_q=bq,
+              block_k=bk, interpret=o.interpret)
+    return qh, kh, vh, kw
+
+
+def _models_layout(x, S):
+    """A heads-major kernel result back in the models' (B, S, H, D)."""
+    return x[:, :, :S].transpose(0, 2, 1, 3)
+
+
+def _flash_forward(q, k, v, o: _FlashOpts):
+    """Pallas forward in the models' (B, S, H, D) layout."""
+    qh, kh, vh, kw = _blocked(q, k, v, o)
+    return _models_layout(flash_attention_kernel(qh, kh, vh, **kw),
+                          q.shape[1])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -190,22 +195,22 @@ def _flash_pallas(q, k, v, o: _FlashOpts):
 
 
 def _flash_pallas_fwd(q, k, v, o):
-    return _flash_forward(q, k, v, o), (q, k, v)
+    qh, kh, vh, kw = _blocked(q, k, v, o)
+    out, lse = flash_attention_kernel(qh, kh, vh, with_lse=True, **kw)
+    return _models_layout(out, q.shape[1]), (q, k, v, out, lse)
 
 
-def _flash_chunked_jnp_bwd(o, res, g):
-    """Backward of the Pallas forward, taken as the VJP of the chunked jnp
-    path (same semantics, recomputes the forward in jnp)."""
-    q, k, v = res
-    with jax.named_scope("flash_attention_bwd_chunked_jnp"):
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: chunked_attention(
-                q_, k_, v_, causal=o.causal, window=o.window,
-                q_offset=o.q_offset, kv_len=o.kv_len, remat=True), q, k, v)
-        return vjp(g)
+def _flash_pallas_bwd(o, res, g):
+    """The Pallas backward: dk/dv and dq kernels on the forward's tiles."""
+    q, k, v, out, lse = res
+    qh, kh, vh, kw = _blocked(q, k, v, o)
+    do = _pad_to(g.transpose(0, 2, 1, 3), 2, kw["block_q"])
+    dq, dk, dv = flash_attention_bwd_kernel(qh, kh, vh, out, lse, do, **kw)
+    return (_models_layout(dq, q.shape[1]), _models_layout(dk, k.shape[1]),
+            _models_layout(dv, k.shape[1]))
 
 
-_flash_pallas.defvjp(_flash_pallas_fwd, _flash_chunked_jnp_bwd)
+_flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 
 def _over_head_shards(fn, q, k, v):
@@ -235,9 +240,9 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
                     block_q=512, block_k=512, q_chunk=512, k_chunk=512):
     """Dispatching attention entry point used by the models.
 
-    ``backend="pallas"`` (the default on TPU) runs the Pallas kernel forward;
-    its gradient is the VJP of ``chunked_attention``.  ``backend="chunked"``
-    (the default elsewhere) runs the jnp path both ways.
+    ``backend="pallas"`` (the default on TPU) runs the Pallas kernels, the
+    forward and its backward.  ``backend="chunked"`` (the default
+    elsewhere) runs the jnp path both ways.
     """
     if backend is None:
         backend = "pallas" if jax.default_backend() == "tpu" else "chunked"

@@ -14,8 +14,8 @@ Where the ops of a scope land in a trace:
   writes the stacked cache under ``attn/cache_write`` instead.
 - ``attn/qkv``, ``attn/cache_write``, ``attn/core``, ``attn/out``: an
   attention block's norm and projections, its cache update, the attention
-  itself (kernel, decode attention, and the jnp backward's
-  ``flash_attention_bwd_chunked_jnp``), and its output projection.
+  itself (the flash kernels, forward and backward, and decode attention),
+  and its output projection.
 - ``mlp``, ``moe``, ``rwkv/time_mix``, ``rwkv/channel_mix``, ``rglru``: the
   other blocks.
 - ``embed``, ``head`` (final norm and logits), ``loss``, ``optimizer``

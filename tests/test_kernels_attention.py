@@ -70,23 +70,28 @@ SCHEDULE_SHAPES = [_kernel_args(c) for c in CASES] + [
     (256, 256, 64, 64, True, 32, 0, 100),            # q blocks seeing nothing
 ]
 
-# per head: (visited, rectangle)
-SCHEDULE_COUNTS = {SCHEDULE_SHAPES[len(CASES)]: (2080, 4096),
-                   SCHEDULE_SHAPES[len(CASES) + 1]: (36, 64)}
+# per head: (visited in q order, visited in kv order, rectangle)
+SCHEDULE_COUNTS = {SCHEDULE_SHAPES[len(CASES)]: (2080, 2080, 4096),
+                   SCHEDULE_SHAPES[len(CASES) + 1]: (36, 36, 64)}
 
 
-@pytest.mark.parametrize("shape", SCHEDULE_SHAPES)
-def test_tile_schedule_matches_brute_force(shape):
-    """The schedule against the per-element mask: visited tiles hold a
-    visible (query, key) pair, each q block's tiles in ascending ``ik``; a
-    q block that sees no key is one fully masked step at ``ik`` 0."""
+@pytest.mark.parametrize("shape,order", [
+    pytest.param(shape, order, id=f"shape{i}" + ("" if order == "by_q"
+                                                else "-by_kv"))
+    for order in ("by_q", "by_kv") for i, shape in enumerate(SCHEDULE_SHAPES)])
+def test_tile_schedule_matches_brute_force(shape, order):
+    """Each order of the schedule against the per-element mask: visited
+    tiles hold a visible (query, key) pair, each q block's tiles in
+    ascending ``ik`` (``by_q``), or each kv block's in ascending ``iq``
+    (``by_kv``); a block that sees nothing is one fully masked step against
+    block 0 of the other side.  Both orders visit the same visible tiles."""
     Sq, Sk, bq, bk, causal, window, qoff, kv_len = shape
     kw = dict(block_q=bq, block_k=bk, causal=causal, window=window,
               q_offset=qoff, kv_len=kv_len)
     kv_len = Sk if kv_len is None else kv_len
     nq, nk = Sq // bq, Sk // bk
     kpos = np.arange(Sk)[None, :]
-    want = []
+    seen = np.zeros((nq, nk), bool)       # tiles holding a visible pair
     for iq in range(nq):
         qpos = qoff + iq * bq + np.arange(bq)[:, None]
         mask = np.broadcast_to(kpos < kv_len, (bq, Sk))
@@ -94,17 +99,32 @@ def test_tile_schedule_matches_brute_force(shape):
             mask = mask & (kpos <= qpos)
         if window is not None:
             mask = mask & (kpos > qpos - window)
-        tiles = mask.reshape(bq, nk, bk)
-        seen = tiles.any(axis=(0, 2))
-        want += [(iq, ik) for ik in np.flatnonzero(seen)] or [(iq, 0)]
+        seen[iq] = mask.reshape(bq, nk, bk).any(axis=(0, 2))
+    if order == "by_q":
+        want = [t for iq in range(nq) for t in
+                [(iq, ik) for ik in np.flatnonzero(seen[iq])] or [(iq, 0)]]
+        major = 0
+    else:
+        want = [t for ik in range(nk) for t in
+                [(iq, ik) for iq in np.flatnonzero(seen[:, ik])] or [(0, ik)]]
+        major = 1
 
-    iq, ik, last, first = unpack_tile(tile_schedule(Sq, Sk, **kw))
+    sched = tile_schedule(Sq, Sk, **kw)
+    iq, ik, last, first = unpack_tile(getattr(sched, order))
     assert list(zip(iq.tolist(), ik.tolist())) == want
-    starts = np.r_[True, iq[1:] != iq[:-1]]
+    blocks = (iq, ik)[major]
+    starts = np.r_[True, blocks[1:] != blocks[:-1]]
     assert first.tolist() == starts.astype(int).tolist()
     assert last.tolist() == np.r_[starts[1:], True].astype(int).tolist()
+
+    def visible(table):
+        tq, tk, _, _ = unpack_tile(table)
+        return {(a, b) for a, b in zip(tq.tolist(), tk.tolist()) if seen[a, b]}
+    assert visible(sched.by_q) == visible(sched.by_kv) == set(
+        zip(*map(np.ndarray.tolist, np.nonzero(seen))))
     counts = tile_counts(Sq, Sk, **kw)
-    assert counts == (len(want), nq * nk)
+    assert counts == (len(sched.by_q), len(sched.by_kv), nq * nk)
+    assert getattr(counts, order) == len(want)
     if shape in SCHEDULE_COUNTS:
         assert counts == SCHEDULE_COUNTS[shape]
 
@@ -179,39 +199,60 @@ def test_chunked_property(b, s, h, g, d, causal):
 
 
 GRAD_CASES = [
-    # B, S, H, KH, D, window
-    (1, 64, 4, 1, 16, None),    # GQA, group 4
-    (2, 48, 4, 2, 16, 20),      # GQA + sliding window, S not a block multiple
-    (1, 40, 2, 2, 32, None),    # MHA
+    # B, S, H, KH, D, Dv, causal, window, dtype
+    (1, 64, 4, 1, 16, 16, True, None, jnp.float32),   # GQA, group 4
+    # GQA + sliding window, S not a block multiple
+    (2, 48, 4, 2, 16, 16, True, 20, jnp.float32),
+    (1, 40, 2, 2, 32, 32, True, None, jnp.float32),   # MHA
+    (2, 32, 4, 2, 24, 16, True, None, jnp.float32),   # MLA: Dv != D
+    (1, 48, 4, 2, 16, 16, False, None, jnp.float32),  # bidirectional
+    # 50 pads to 64: kv_len masks the padded keys, padded queries drop out
+    (2, 50, 4, 2, 16, 16, True, None, jnp.float32),
+    (1, 64, 4, 2, 32, 32, True, None, jnp.bfloat16),  # bf16 vs f32 oracle
 ]
 
 
 @pytest.mark.parametrize("case", GRAD_CASES)
 def test_pallas_grad_matches_reference_grad(case):
-    """jax.grad through the Pallas forward (custom VJP) == grad of the naive
-    oracle.  f32 throughout; the two differ only in summation order over at
-    most 64 keys of O(1) terms, so 2e-5 absolute/relative is ~100 ulp."""
-    B, S, H, KH, D, window = case
+    """jax.grad through the Pallas kernels (forward and the dkv/dq backward)
+    == grad of the naive oracle, run in f32 on the same input values.
+
+    f32: the two differ only in summation order over at most 64 keys of
+    O(1) terms, so 2e-5 absolute/relative is ~100 ulp.  bf16: the kernels
+    round to bf16 (unit roundoff u = 2**-8) the operands of each backward
+    matmul (p, ds; do as given), the output o that delta is summed from,
+    and each gradient; every term of a gradient then carries at most ~3u
+    of its size and the gradient at most ~4u of the largest gradient
+    (readings over six seeds: at most 1.9u)."""
+    B, S, H, KH, D, Dv, causal, window, dtype = case
     ks = jax.random.split(jax.random.PRNGKey(7 + S), 4)
-    q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, S, KH, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, S, KH, D), jnp.float32)
-    ct = jax.random.normal(ks[3], (B, S, H, D), jnp.float32)
+    q = jax.random.normal(ks[0], (B, S, H, D), dtype)
+    k = jax.random.normal(ks[1], (B, S, KH, D), dtype)
+    v = jax.random.normal(ks[2], (B, S, KH, Dv), dtype)
+    ct = jax.random.normal(ks[3], (B, S, H, Dv), jnp.float32)
 
     def loss_pallas(q, k, v):
-        out = flash_attention(q, k, v, causal=True, window=window,
+        out = flash_attention(q, k, v, causal=causal, window=window,
                               backend="pallas", interpret=True,
                               block_q=16, block_k=16)
-        return jnp.sum(out * ct)
+        return jnp.sum(out.astype(jnp.float32) * ct)
 
     def loss_ref(q, k, v):
-        return jnp.sum(attention_reference(q, k, v, causal=True,
-                                           window=window) * ct)
+        f32 = lambda x: x.astype(jnp.float32)
+        return jnp.sum(attention_reference(f32(q), f32(k), f32(v),
+                                           causal=causal, window=window) * ct)
 
-    assert "pallas_call" in str(jax.make_jaxpr(
-        jax.grad(loss_pallas, argnums=(0, 1, 2)))(q, k, v))
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss_pallas, argnums=(0, 1, 2)))(
+        q, k, v))
+    assert "flash_attention_bwd_dkv" in jaxpr
+    assert "flash_attention_bwd_dq" in jaxpr
+    assert "scan" not in jaxpr     # no chunked_attention in the gradient
     got = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
     want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   atol=2e-5, rtol=2e-5)
+        assert g.dtype == dtype
+        g, w = np.asarray(g, np.float32), np.asarray(w)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, w, atol=2e-5, rtol=2e-5)
+        else:
+            assert np.max(np.abs(g - w)) <= 4 * 2**-8 * np.max(np.abs(w))

@@ -56,9 +56,9 @@ def _compile_for_chip(fn, *args):
 
 
 # yi-9b attention widths: 32 query heads, 4 kv heads, head_dim 128
-def _yi_qkv(sharding, S):
-    q = jax.ShapeDtypeStruct((1, S, 32, 128), jnp.bfloat16, sharding=sharding)
-    kv = jax.ShapeDtypeStruct((1, S, 4, 128), jnp.bfloat16, sharding=sharding)
+def _yi_qkv(sharding, S, B=1):
+    q = jax.ShapeDtypeStruct((B, S, 32, 128), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((B, S, 4, 128), jnp.bfloat16, sharding=sharding)
     return q, kv, kv
 
 
@@ -72,13 +72,51 @@ def test_flash_attention_forward_compiles(one_chip, no_compile_cache, S):
 
 
 def test_flash_attention_grad_compiles(one_chip, no_compile_cache):
+    """The gradient at the training cell's shapes (batch 2 x 4096, bf16,
+    512 blocks): the forward and both backward kernels, no jnp attention."""
     # a loss whose gradient needs the forward output, so the kernel stays in
     def loss(q, k, v):
         out = flash_attention(q, k, v, backend="pallas")
         return jnp.sum(jnp.square(out.astype(jnp.float32)))
 
-    _compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                      *_yi_qkv(one_chip, 32768))
+    hlo = _compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                            *_yi_qkv(one_chip, 4096, B=2))
+    import re
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq"):
+        assert re.search(rf"%\w*{name}\S* = .* custom-call\(", hlo), name
+    assert " while(" not in hlo
+
+
+def test_train_step_fits_one_chip(one_chip, no_compile_cache, monkeypatch):
+    """yi-9b's training step as the benchmark's training cell runs it (4
+    layers, 8000 vocabulary ids, batch 2 x 4096, remat full, f32 AdamW
+    state, the state donated), with the kernels' chip path: its program
+    holds the Pallas attention backward and fits one v5e's 16 GB."""
+    import dataclasses
+    import functools
+
+    from repro.configs import get_config
+    from repro.models import attention, lm
+    from repro.optim import init_train_state
+    from repro.train import make_train_step
+
+    monkeypatch.setattr(attention, "flash_attention", functools.partial(
+        flash_attention, backend="pallas"))
+    cfg = dataclasses.replace(get_config("yi-9b"), n_layers=4, vocab=8000)
+    on_chip = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    state = on_chip(jax.eval_shape(init_train_state,
+                                   lm.abstract_params(cfg, jnp.bfloat16)))
+    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip)
+    step = jax.jit(make_train_step(cfg, warmup=1, remat="full"),
+                   donate_argnums=0)
+    compiled = step.lower(state, {"tokens": tokens, "labels": tokens}).compile()
+    assert "%flash_attention_bwd_dkv" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 16e9, held
 
 
 def test_flash_attention_head_sharded_compiles(topo, no_compile_cache):
